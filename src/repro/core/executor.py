@@ -194,6 +194,10 @@ class GangExecutor:
         self.watchdog_factor = watchdog_factor
         self._inflight_info: Dict[int, tuple] = {}
         self.watchdog_aborts: List[Tuple[str, int, int, float]] = []
+        # first exception a quantum (or a lane's scheduling code) raised:
+        # it ends the run, and run() re-raises it once the workers stop
+        self._error: Optional[BaseException] = None
+        self._failed = threading.Event()
 
     # compatibility dict views over the executor.* metric counters
     @property
@@ -670,6 +674,17 @@ class GangExecutor:
         return first
 
     # ------------------------------------------------------------------
+    def _lane_main(self, lane: int):
+        try:
+            self._worker(lane)
+        except BaseException as e:       # re-raised by run()
+            with self._wake:
+                if self._error is None:
+                    self._error = e
+                self._stop = True
+                self._wake.notify_all()
+            self._failed.set()
+
     def _worker(self, lane: int):
         prev: Optional[Thread] = None
         while True:
@@ -794,8 +809,11 @@ class GangExecutor:
 
     # ------------------------------------------------------------------
     def run(self, duration_s: float):
+        """Run the lanes for ``duration_s`` seconds and return the stats.
+        The first exception any quantum raises stops every lane early and
+        is re-raised here after the workers have stopped."""
         self._t0 = time.monotonic()
-        workers = [threading.Thread(target=self._worker, args=(lane,),
+        workers = [threading.Thread(target=self._lane_main, args=(lane,),
                                     daemon=True)
                    for lane in range(self.n_lanes)]
         for w in workers:
@@ -808,12 +826,14 @@ class GangExecutor:
             threading.Thread(target=self._watchdog_monitor,
                              args=(min(max(tick, 0.001), 0.05),),
                              daemon=True).start()
-        time.sleep(duration_s)
+        self._failed.wait(duration_s)
         with self._wake:
             self._stop = True
             self._wake.notify_all()
         for w in workers:
             w.join(timeout=5.0)
+        if self._error is not None:
+            raise self._error
         self.trace.finish_view()
         return {
             "response_times": self.response_times,

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -81,7 +80,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
                          block_q: int = 128, block_k: int = 128,
-                         group: int = 1, interpret: bool = True):
+                         group: int = 1, interpret: bool = False):
     """q: (B*Hq, Sq, D); k, v: (B*Hkv, Sk, D); group = Hq // Hkv per batch
     element. ``q`` rows are ordered (b, h); kv row for q row i is
     (i // (Hkv*group)) * Hkv + (i % (Hkv*group)) // group.
@@ -121,7 +120,7 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

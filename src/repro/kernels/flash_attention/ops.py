@@ -2,7 +2,11 @@
 
 Accepts the model-layer layout (B, S, H, D); transposes to the kernel's
 (B*H, S, D) layout; handles GQA via the kernel's index-map grouping.
-``interpret`` defaults to True off-TPU (CPU validation) and False on TPU.
+``interpret=True`` runs the kernel body in the Pallas interpreter (CPU
+validation); the default compiles it for the TPU.
+
+No model path calls this kernel: the models attend through the XLA path
+(``repro.models.layers.flash_attention_jnp`` / ``masked_attention``).
 """
 from __future__ import annotations
 
@@ -14,18 +18,12 @@ import jax.numpy as jnp
 from repro.kernels.flash_attention.flash_attention import flash_attention_bhsd
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k",
                                    "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool | None = None):
+                    interpret: bool = False):
     """q: (B, Sq, Hq, D); k, v: (B, Sk, Hkv, D) -> (B, Sq, Hq, D)."""
-    if interpret is None:
-        interpret = _default_interpret()
     B, Sq, Hq, D = q.shape
     _, Sk, Hkv, _ = k.shape
     G = Hq // Hkv

@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _gmm_kernel(cnt_ref, x_ref, w_ref, o_ref, acc_scr, *, block_c: int,
                 block_d: int, n_d: int):
@@ -30,7 +28,7 @@ def _gmm_kernel(cnt_ref, x_ref, w_ref, o_ref, acc_scr, *, block_c: int,
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    count = cnt_ref[0]
+    count = cnt_ref[e]
     row_start = ci * block_c
 
     @pl.when(row_start < count)
@@ -49,7 +47,7 @@ def _gmm_kernel(cnt_ref, x_ref, w_ref, o_ref, acc_scr, *, block_c: int,
 
 
 def moe_gmm(x, w, counts, *, block_c: int = 128, block_f: int = 128,
-            block_d: int = 128, interpret: bool = True):
+            block_d: int = 128, interpret: bool = False):
     """x: (E, C, D); w: (E, D, F); counts: (E,) int32 -> out (E, C, F).
 
     Rows >= counts[e] are treated as padding (zeroed in the output and
@@ -69,7 +67,8 @@ def moe_gmm(x, w, counts, *, block_c: int = 128, block_f: int = 128,
         kernel,
         grid=(E, nc, nf, nd),
         in_specs=[
-            pl.BlockSpec((1,), lambda e, ci, fi, di: (e,)),
+            # per-expert row counts: the whole vector sits in SMEM
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_c, block_d),
                          lambda e, ci, fi, di: (e, ci, di)),
             pl.BlockSpec((1, block_d, block_f),
@@ -79,7 +78,7 @@ def moe_gmm(x, w, counts, *, block_c: int = 128, block_f: int = 128,
                                lambda e, ci, fi, di: (e, ci, fi)),
         out_shape=jax.ShapeDtypeStruct((E, C, F), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_c, block_f), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -9,8 +9,6 @@ from repro.kernels.rglru_scan.rglru_scan import rglru_scan_bc
 
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
-def rglru_scan(log_a, b, *, chunk: int = 256, interpret: bool | None = None):
+def rglru_scan(log_a, b, *, chunk: int = 256, interpret: bool = False):
     """log_a, b: (B, S, C) -> (B, S, C) recurrence outputs."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return rglru_scan_bc(log_a, b, chunk=chunk, interpret=interpret)

@@ -11,11 +11,9 @@ from repro.kernels.ssd_scan.ssd_scan import ssd_scan_bh
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(xh, dt, Bm, Cm, A, *, chunk: int = 128,
-             interpret: bool | None = None):
+             interpret: bool = False):
     """xh: (B, S, H, P); dt: (B, S, H); Bm, Cm: (B, S, N) (shared across
     heads); A: (H,). Returns (y: (B, S, H, P), h: (B, H, P, N))."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     B, S, H, P = xh.shape
     N = Bm.shape[-1]
     x2 = xh.transpose(0, 2, 1, 3).reshape(B * H, S, P)

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, y_ref, hout_ref, h_scr,
                 *, chunk: int, n_chunks: int):
@@ -38,31 +36,39 @@ def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, y_ref, hout_ref, h_scr,
     dt = dt_ref[0].astype(jnp.float32)        # (Q, 1)
     Bm = b_ref[0].astype(jnp.float32)         # (Q, N)
     Cm = c_ref[0].astype(jnp.float32)         # (Q, N)
-    A = a_ref[0, 0]                           # scalar (per head)
+    A = a_ref[pl.program_id(0)]               # scalar (per head), SMEM
 
-    logd = dt[:, 0] * A                       # (Q,)
-    Lc = jnp.cumsum(logd)                     # (Q,)
-    Ltot = Lc[-1]
-
-    # intra-chunk
-    CB = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # (Q,Q)
-    diff = Lc[:, None] - Lc[None, :]
+    # every per-token vector stays a (Q, 1) column or a (1, Q) row: Mosaic
+    # has no cumsum, so the prefix sums are a lower-triangular matmul. All
+    # dots run at full f32 precision (the MXU default rounds to bf16).
+    exact = dict(precision=jax.lax.Precision.HIGHEST,
+                 preferred_element_type=jnp.float32)
     iq = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jq = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    M = jnp.where(iq >= jq, jnp.exp(diff), 0.0) * CB * dt[:, 0][None, :]
-    y = jax.lax.dot(M, x, preferred_element_type=jnp.float32)     # (Q,P)
+    causal = iq >= jq
+    tril = causal.astype(jnp.float32)
+    logd = dt * A                                                 # (Q,1)
+    Lc = jax.lax.dot(tril, logd, **exact)                         # (Q,1)
+    Lc_row = jax.lax.dot_general(logd, tril, (((0,), (1,)), ((), ())),
+                                 **exact)                         # (1,Q)
+    Ltot = jnp.sum(logd, axis=0, keepdims=True)                   # (1,1)
+
+    # intra-chunk: M[i, j] = exp(Lc_i - Lc_j) (C_i . B_j) for j <= i,
+    # applied to dt_j x_j
+    CB = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
+                             **exact)                             # (Q,Q)
+    M = jnp.where(causal, jnp.exp(Lc - Lc_row), 0.0) * CB
+    y = jax.lax.dot(M, dt * x, **exact)                          # (Q,P)
 
     # inter-chunk: y += exp(Lc) * C @ h_prev^T   (h: (P,N))
     h_prev = h_scr[...]
-    y += jnp.exp(Lc)[:, None] * jax.lax.dot_general(
-        Cm, h_prev, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    y += jnp.exp(Lc) * jax.lax.dot_general(
+        Cm, h_prev, (((1,), (1,)), ((), ())), **exact)
 
     # state update: h_new = exp(Ltot) h_prev + x^T @ (exp(Ltot-Lc)*dt*B)
-    w = (jnp.exp(Ltot - Lc) * dt[:, 0])[:, None] * Bm               # (Q,N)
+    w = jnp.exp(Ltot - Lc) * dt * Bm                              # (Q,N)
     h_scr[...] = jnp.exp(Ltot) * h_prev + jax.lax.dot_general(
-        x, w, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        x, w, (((0,), (0,)), ((), ())), **exact)
 
     y_ref[0] = y.astype(y_ref.dtype)
 
@@ -72,7 +78,7 @@ def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, y_ref, hout_ref, h_scr,
 
 
 def ssd_scan_bh(x, dt, Bm, Cm, A, *, chunk: int = 128,
-                interpret: bool = True):
+                interpret: bool = False):
     """x: (BH, S, P); dt: (BH, S, 1); Bm, Cm: (BH, S, N); A: (BH, 1).
 
     Returns (y: (BH, S, P), h_final: (BH, P, N)). fp32 recommended.
@@ -92,7 +98,8 @@ def ssd_scan_bh(x, dt, Bm, Cm, A, *, chunk: int = 128,
             pl.BlockSpec((1, chunk, 1), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((1, chunk, N), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((1, chunk, N), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, 1), lambda bh, ci: (bh, 0)),
+            # one scalar per (batch, head): the whole vector sits in SMEM
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, P), lambda bh, ci: (bh, ci, 0)),
@@ -103,8 +110,8 @@ def ssd_scan_bh(x, dt, Bm, Cm, A, *, chunk: int = 128,
             jax.ShapeDtypeStruct((BH, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(x, dt, Bm, Cm, A)
+    )(x, dt, Bm, Cm, A.reshape(BH).astype(jnp.float32))
     return y, h

@@ -7,17 +7,57 @@ sharded data loading, FSDP/TP sharding, checkpoint/restart (use
 from __future__ import annotations
 
 import argparse
+import dataclasses
+from typing import Optional
 
 import jax
-import numpy as np
 
 from repro.configs import get_config, reduced
 from repro.configs.base import ParallelConfig
 from repro.data.pipeline import DataConfig
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models.model import build_model
 from repro.training.optimizer import OptConfig, Optimizer
 from repro.training.runner import RunnerConfig, SimulatedFailure, TrainRunner
+
+
+def train(arch: str = "qwen2-7b", *, full_size: bool = False,
+          steps: int = 60, batch: int = 8, seq: int = 128, lr: float = 3e-3,
+          ckpt_dir: str = "/tmp/repro_ckpt", ckpt_every: int = 20,
+          fail_at_step: Optional[int] = None, data: Optional[str] = None,
+          mesh=None):
+    """Train ``arch`` with params and optimizer state FSDP-sharded over
+    ``mesh`` (default: every local device on ``data``); a simulated
+    failure restarts once from the latest checkpoint. Returns
+    ``(metrics_log, final_state)``."""
+    cfg = get_config(arch)
+    if not full_size:
+        cfg = reduced(cfg)
+    if mesh is None:
+        mesh = make_local_mesh(len(jax.devices()), 1)
+    parallel = ParallelConfig(param_dtype="float32", compute_dtype="float32",
+                              q_block=64, kv_block=64)
+    api = build_model(cfg, parallel, mesh)
+    opt = Optimizer(OptConfig(name="adamw", lr=lr, warmup=10,
+                              decay_steps=max(steps, 20)))
+    data_cfg = DataConfig(
+        seq_len=seq, global_batch=batch,
+        vocab_size=cfg.vocab_size, path=data,
+        n_vision_tokens=cfg.n_vision_tokens, d_model=cfg.d_model,
+        n_frames=cfg.n_encoder_frames if cfg.family == "audio" else 0)
+    rc = RunnerConfig(total_steps=steps, ckpt_every=ckpt_every,
+                      ckpt_dir=ckpt_dir, fail_at_step=fail_at_step)
+    runner = TrainRunner(api, opt, data_cfg, rc)
+    try:
+        state = runner.run()
+    except SimulatedFailure as e:
+        print(f"[ft] {e}; restarting from latest checkpoint...")
+        runner2 = TrainRunner(api, opt, data_cfg,
+                              dataclasses.replace(rc, fail_at_step=None))
+        state = runner2.run()
+        runner.metrics_log.extend(runner2.metrics_log)
+    return runner.metrics_log, state
 
 
 def main():
@@ -35,36 +75,14 @@ def main():
     ap.add_argument("--data", default=None, help="memmapped token file")
     args = ap.parse_args()
 
-    cfg = get_config(args.arch)
-    if not args.full_size:
-        cfg = reduced(cfg)
-    mesh = make_local_mesh(len(jax.devices()), 1)
-    parallel = ParallelConfig(param_dtype="float32", compute_dtype="float32",
-                              q_block=64, kv_block=64)
-    api = build_model(cfg, parallel, mesh)
-    opt = Optimizer(OptConfig(name="adamw", lr=args.lr, warmup=10,
-                              decay_steps=max(args.steps, 20)))
-    data_cfg = DataConfig(
-        seq_len=args.seq, global_batch=args.batch,
-        vocab_size=cfg.vocab_size, path=args.data,
-        n_vision_tokens=cfg.n_vision_tokens, d_model=cfg.d_model,
-        n_frames=cfg.n_encoder_frames if cfg.family == "audio" else 0)
-    rc = RunnerConfig(total_steps=args.steps, ckpt_every=args.ckpt_every,
-                      ckpt_dir=args.ckpt_dir, fail_at_step=args.fail_at_step)
-    runner = TrainRunner(api, opt, data_cfg, rc)
-    try:
-        runner.run()
-    except SimulatedFailure as e:
-        print(f"[ft] {e}; restarting from latest checkpoint...")
-        runner2 = TrainRunner(api, opt, data_cfg,
-                              RunnerConfig(total_steps=args.steps,
-                                           ckpt_every=args.ckpt_every,
-                                           ckpt_dir=args.ckpt_dir))
-        runner2.run()
-        runner.metrics_log.extend(runner2.metrics_log)
-    first = runner.metrics_log[0]["loss"] if runner.metrics_log else None
-    last = runner.metrics_log[-1]["loss"] if runner.metrics_log else None
-    print(f"[train] {args.arch}: steps={len(runner.metrics_log)} "
+    enable_compile_cache()
+    log, _ = train(args.arch, full_size=args.full_size, steps=args.steps,
+                   batch=args.batch, seq=args.seq, lr=args.lr,
+                   ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
+                   fail_at_step=args.fail_at_step, data=args.data)
+    first = log[0]["loss"] if log else None
+    last = log[-1]["loss"] if log else None
+    print(f"[train] {args.arch}: steps={len(log)} "
           f"loss {first:.4f} -> {last:.4f}")
 
 

@@ -10,8 +10,9 @@ Conventions
 * Attention comes in two XLA-path flavours:
   - ``flash_attention_jnp``: double-blocked online-softmax attention
     (lax.scan over q-blocks and kv-chunks) — O(block) memory at any sequence
-    length; this mirrors the Pallas kernel in ``repro.kernels.flash_attention``
-    which replaces it on real TPUs.
+    length. The Pallas kernel in ``repro.kernels.flash_attention`` uses the
+    same blocking, but no model path calls it: this XLA path is what runs on
+    the TPU too.
   - ``decode_attention``: single-query attention against a KV cache.
 """
 from __future__ import annotations
@@ -54,10 +55,24 @@ def _init_one(rng, d: ParamDef, dtype):
     return (jax.random.normal(rng, d.shape, jnp.float32) * std).astype(dtype)
 
 
-def init_params(rng, defs, dtype=jnp.float32):
+@partial(jax.jit, static_argnums=(1, 2, 3))
+def init_leaf(rng, d: ParamDef, dtype, sharding=None):
+    """Draw one leaf straight into ``dtype`` (and onto ``sharding``) in one
+    program, so the float32 draw is fused into the cast and never sits in
+    device memory beside the params already placed."""
+    x = _init_one(rng, d, dtype)
+    if sharding is not None:
+        x = jax.lax.with_sharding_constraint(x, sharding)
+    return x
+
+
+def init_params(rng, defs, dtype=jnp.float32, shardings=None):
     leaves, treedef = jax.tree.flatten(defs, is_leaf=is_def)
     rngs = jax.random.split(rng, len(leaves))
-    vals = [_init_one(r, d, dtype) for r, d in zip(rngs, leaves)]
+    shards = [None] * len(leaves) if shardings is None else \
+        treedef.flatten_up_to(shardings)
+    vals = [init_leaf(r, d, dtype, s)
+            for r, d, s in zip(rngs, leaves, shards)]
     return jax.tree.unflatten(treedef, vals)
 
 

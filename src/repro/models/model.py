@@ -110,7 +110,10 @@ class ModelApi:
 
     # ---- params ----------------------------------------------------------
     def init(self, rng) -> Any:
-        return L.init_params(rng, self.defs, DTYPES[self.parallel.param_dtype])
+        """Random params, each leaf drawn on its sharding (FSDP/TP)."""
+        shardings = None if self.mesh is None else self.param_shardings()
+        return L.init_params(rng, self.defs, DTYPES[self.parallel.param_dtype],
+                             shardings)
 
     def param_shapes(self) -> Any:
         return L.param_shapes(self.defs, DTYPES[self.parallel.param_dtype])

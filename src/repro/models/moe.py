@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
@@ -173,11 +172,11 @@ def _moe_sp(ctx: Ctx, p, x):
     def body(x_loc, w_router, wg, wu, wd):
         return _moe_local_a2a(cfg, model_axis, n, x_loc, w_router, wg, wu, wd)
 
-    y, lb, rz = shard_map(
+    y, lb, rz = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(x_spec, w_full, e_spec, e_spec, e_spec),
         out_specs=(x_spec, P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["wg"], p["wu"], p["wd"])
     return y, {"load_balance": lb, "router_z": rz}
 
@@ -263,11 +262,11 @@ def _moe_replicated(ctx: Ctx, p, x):
         aux = aux_losses(probs, tope, E)
         return (y.reshape(B, S, D), aux["load_balance"], aux["router_z"])
 
-    y, lb, rz = shard_map(
+    y, lb, rz = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(x_spec, P(None, None), e_spec, e_spec, e_spec),
         out_specs=(x_spec, P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["wg"], p["wu"], p["wd"])
     return y, {"load_balance": lb, "router_z": rz}
 
@@ -331,11 +330,11 @@ def _moe_weight_stationary(ctx: Ctx, p, x):
                                          d_idx * B_loc, B_loc, axis=0)
         return y, aux["load_balance"], aux["router_z"]
 
-    y, lb, rz = shard_map(
+    y, lb, rz = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(x_spec, P(None, None), wg_spec, wg_spec, wd_spec),
         out_specs=(x_spec, P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["wg"], p["wu"], p["wd"])
     return y, {"load_balance": lb, "router_z": rz}
 

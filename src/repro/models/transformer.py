@@ -25,7 +25,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import ModelConfig, ParallelConfig
 from repro.models import layers as L
@@ -86,12 +85,12 @@ def _sp_in_project(ctx: "Ctx", x, ws):
         h = jax.lax.all_gather(xl, model_axis, axis=1, tiled=True)
         return tuple(h @ w for w in wl)
 
-    outs = shard_map(
+    outs = jax.shard_map(
         local, mesh=ctx.mesh,
         in_specs=(P(bspec, model_axis, None),)
         + tuple(P(None, model_axis) for _ in ws),
         out_specs=tuple(P(bspec, None, model_axis) for _ in ws),
-        check_rep=False)(x, *ws)
+        check_vma=False)(x, *ws)
     return list(outs)
 
 
@@ -113,11 +112,11 @@ def _rs_project(ctx: "Ctx", h, w):
         return jax.lax.psum_scatter(part, model_axis, scatter_dimension=1,
                                     tiled=True)
 
-    return shard_map(local, mesh=ctx.mesh,
-                     in_specs=(P(bspec, None, model_axis),
-                               P(model_axis, None)),
-                     out_specs=P(bspec, model_axis, None),
-                     check_rep=False)(h, w)
+    return jax.shard_map(local, mesh=ctx.mesh,
+                         in_specs=(P(bspec, None, model_axis),
+                                   P(model_axis, None)),
+                         out_specs=P(bspec, model_axis, None),
+                         check_vma=False)(h, w)
 
 
 # --------------------------------------------------------------------------
@@ -205,8 +204,8 @@ def _cp_attention(ctx: Ctx, q, k, v, *, causal=True, window=0):
             softcap=ctx.cfg.logit_softcap,
             q_block=min(ctx.q_block, s_loc), kv_block=ctx.kv_block)
 
-    return shard_map(local, mesh=ctx.mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_rep=False)(q, k, v)
+    return jax.shard_map(local, mesh=ctx.mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def attn_apply(ctx: Ctx, p, x, cache: Optional[dict] = None,

@@ -61,11 +61,16 @@ class ServingEngine:
 
     # ------------------------------------------------------------------
     def warmup(self, prompt_len: int):
-        """Compile prefill+decode ahead of serving, then reset to fresh
-        state (the decode cache is donated, so no snapshot/restore)."""
-        dummy = Request(rid=-1, prompt=np.zeros((prompt_len,), np.int32),
-                        max_new=1)
-        self.add_request(dummy)
+        """Compile everything a serving step runs, then reset to fresh
+        state (the decode cache is donated, so no snapshot/restore): the
+        prefill, the insertion into every slot, and two decode steps — the
+        second takes the cache the first returned, whose placement differs
+        from the fresh cache's and so compiles its own program."""
+        for i in range(self.B):
+            self.add_request(Request(
+                rid=-1 - i, prompt=np.zeros((prompt_len,), np.int32),
+                max_new=3))
+        self.decode_step()
         self.decode_step()
         self.cache = self._empty_cache(self.cache["k"].dtype)
         self.pos = jnp.zeros((self.B,), jnp.int32)

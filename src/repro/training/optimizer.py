@@ -62,7 +62,7 @@ class Optimizer:
     # ---- adamw -------------------------------------------------------------
     def _adamw_init(self, params):
         dt = DTYPES[self.cfg.state_dtype]
-        z = lambda p: jnp.zeros(p.shape, dt)
+        z = lambda p: jnp.zeros_like(p, dtype=dt)   # keeps p's sharding
         return {"m": jax.tree.map(z, params), "v": jax.tree.map(z, params),
                 "count": jnp.zeros((), jnp.int32)}
 

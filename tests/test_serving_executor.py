@@ -109,6 +109,32 @@ def test_executor_throttles_best_effort():
     assert results[0.0] < results[1e9] * 0.2, results
 
 
+@pytest.mark.parametrize("failing", ["rt", "be"])
+def test_executor_run_reraises_quantum_failure(failing):
+    """A quantum that raises ends the run early, and run() re-raises it."""
+    class Boom(RuntimeError):
+        pass
+
+    def rt_fn(lane, idx):
+        if failing == "rt" and idx == 3:
+            raise Boom("rt")
+        time.sleep(0.001)
+
+    def be_fn(lane):
+        if failing == "be":
+            raise Boom("be")
+        time.sleep(0.001)
+
+    ex = GangExecutor(n_lanes=2, regulation_interval_s=0.01)
+    ex.submit_rt(RTJob("rt", rt_fn, lanes=(0,), prio=5, period_s=0.005,
+                       budget_bytes=1e9, n_jobs=100))
+    ex.submit_be(BEJob("be", be_fn, lanes=(1,), bytes_per_quantum=1.0))
+    t0 = time.monotonic()
+    with pytest.raises(Boom, match=failing):
+        ex.run(5.0)
+    assert time.monotonic() - t0 < 4.0
+
+
 def test_executor_records_stragglers():
     slow = {"n": 0}
 

@@ -21,6 +21,7 @@ import numpy as np
 import jax, jax.numpy as jnp
 from repro.configs import get_config, reduced
 from repro.configs.base import ParallelConfig, MoEConfig
+from repro.launch.mesh import make_local_mesh
 from repro.models.model import build_model
 from repro.roofline.hlo_analysis import analyze
 
@@ -40,7 +41,7 @@ for arch in ["qwen2-7b", "olmoe-1b-7b"]:
     losses = {}
     hlo_stats = {}
     for name, (d, m) in {"single": (1, 1), "dist": (2, 4)}.items():
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_local_mesh(d, m)
         par = ParallelConfig(param_dtype="float32", compute_dtype="float32",
                              q_block=8, kv_block=8,
                              sequence_parallel=(name == "dist"))
