@@ -11,9 +11,14 @@ Differences from the kernel implementation, modeled explicitly:
   boundaries, contributing the blocking term B_i = max lower-prio quantum to
   RTA (core/rta.py);
 * throttling is admission-based (quantum bytes known from
-  ``compiled.cost_analysis()``) rather than perf-counter-reactive;
-* straggler mitigation: per-quantum deadline monitor with optional
-  speculative backup dispatch of idempotent quanta onto idle lanes.
+  ``compiled.cost_analysis()``) rather than perf-counter-reactive.
+
+Every run keeps a flight recorder (obs/flight.py, DESIGN.md §12.4):
+each RT release's phases per gang lane on its ``_JobInstance``, the
+regulator's per-window admission rows, and a monitor thread's
+``host.tick`` rows (with ``host.gc`` rows from a ``gc.callbacks`` hook)
+that name the host's stalls. ``obs.flight.last_run()`` returns the
+latest run's record.
 
 Virtual gangs (DESIGN.md §2.4): ``submit_vgang`` flattens a formed
 ``vgang.formation.VirtualGang`` onto disjoint lane blocks (the same
@@ -29,9 +34,13 @@ Works with any callables; benchmarks bind jitted JAX functions per lane.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import heapq
 import itertools
+import math
+import resource
+import sys
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -40,6 +49,7 @@ from repro.core.gang import RTTask, Thread, _ids
 from repro.core.glock import GangScheduler
 from repro.core.throttle import BandwidthRegulator
 from repro.core.tracing import Trace
+from repro.obs import flight
 
 # job uids share the RTTask counter so that virtual-gang members —
 # whose RTJobs reuse the member task's uid (submit_vgang) — can never
@@ -86,22 +96,26 @@ class BEJob:
     uid: int = dataclasses.field(default_factory=lambda: next(_uid))
 
 
+# a release's phase stamps, per gang lane i: phase[3 * i + PICKED] ...
+PICKED, ADMITTED, DONE = 0, 1, 2
+
+
 @dataclasses.dataclass
 class _JobInstance:
     job: RTJob
     index: int
     release: float
     remaining_lanes: set
-    start: Optional[float] = None
     finish: Optional[float] = None
     aborted: bool = False          # watchdog killed this gang release
+    # flight recorder: for the gang's i-th lane, when the lane picked
+    # this gang, was past the barrier and admission, and retired
+    phase: List[float] = dataclasses.field(default_factory=list)
 
 
 class GangExecutor:
     def __init__(self, n_lanes: int, *, enabled: bool = True,
                  regulation_interval_s: float = 0.010,
-                 straggler_factor: float = 3.0,
-                 backup_dispatch: bool = False,
                  budget_policy=None, reclaim: bool = False,
                  watchdog_s: Optional[float] = None,
                  watchdog_factor: Optional[float] = None,
@@ -148,7 +162,8 @@ class GangExecutor:
         self.reg = BandwidthRegulator(n_lanes,
                                       interval=regulation_interval_s,
                                       mode="admission", reclaim=reclaim,
-                                      metrics=self._mreg)
+                                      metrics=self._mreg,
+                                      record_history=True)
         self.trace = Trace(n_lanes)
         self.rt_jobs: List[RTJob] = []
         self.be_jobs: List[BEJob] = []
@@ -165,9 +180,6 @@ class GangExecutor:
         self._lock = threading.Lock()
         self._wake = threading.Condition(self._lock)
         self._stop = False
-        self.straggler_factor = straggler_factor
-        self.backup_dispatch = backup_dispatch
-        self.stragglers: List[Tuple[str, int, float]] = []
         self.response_times: Dict[str, List[float]] = {}
         # per-name obs.metrics counters (executor.* series); the
         # be_quanta / rt_stalls / aborted properties expose the
@@ -175,7 +187,6 @@ class GangExecutor:
         self._be_q: Dict[str, object] = {}
         self._stall_c: Dict[str, object] = {}
         self._abort_c: Dict[str, object] = {}
-        self._ema: Dict[str, float] = {}
         self._budget_sig = None     # last glock state budgets derive from
         # gang prios whose in-flight quanta were still draining when the
         # current leader's budgets were applied: until they retire, the
@@ -198,6 +209,10 @@ class GangExecutor:
         # it ends the run, and run() re-raises it once the workers stop
         self._error: Optional[BaseException] = None
         self._failed = threading.Event()
+        # the monitor thread's stop signal and what it records
+        self._halt = threading.Event()
+        self._ticks = flight.ring()
+        self._tick_cpu_s = 0.0
 
     # compatibility dict views over the executor.* metric counters
     @property
@@ -464,7 +479,8 @@ class GangExecutor:
             if now + 1e-9 >= next_rel:
                 insts.append(_JobInstance(
                     job=job, index=n, release=next_rel,
-                    remaining_lanes=set(job.lanes)))
+                    remaining_lanes=set(job.lanes),
+                    phase=[math.nan] * (3 * len(job.lanes))))
                 seq = next(self._ready_seq)
                 for lane in job.lanes:
                     heapq.heappush(self._ready[lane],
@@ -621,18 +637,59 @@ class GangExecutor:
         return self.watchdog_s is not None or any(
             self._watchdog_deadline(j) is not None for j in self.rt_jobs)
 
-    def _watchdog_monitor(self, tick: float):
+    def _monitor_tick(self) -> float:
+        """The monitor thread's period: one regulation interval, or the
+        watchdog's finer scan period when one is armed."""
+        tick = self.reg.interval
+        if self._watchdog_armed():
+            deadlines = [d for d in (self._watchdog_deadline(j)
+                                     for j in self.rt_jobs)
+                         if d is not None]
+            scan = min(deadlines) / 4 if deadlines else 0.01
+            tick = min(tick, min(max(scan, 0.001), 0.05))
+        return tick
+
+    def _monitor(self, tick: float, watchdog: bool,
+                 gc_probe: flight.GcProbe):
+        """Wake at every multiple of ``tick``: write a ``host.tick`` row
+        (lateness against the planned instant and what the process did
+        since the previous tick), then, when armed, scan for watchdog
+        victims. Instants a stall skipped are not made up."""
+        cpu_start = time.thread_time()
+        gc_s = gc_probe.paused(self._now())
+        ru = resource.getrusage(resource.RUSAGE_SELF)
+        t_plan = tick
         while True:
-            with self._wake:
-                if self._stop:
-                    return
-                now = self._now()
-                victims = [(ln, info[0], info[1])
-                           for ln, info in self._inflight_info.items()
-                           if info[3] is not None and now - info[2] > info[3]]
-            for ln, uid, idx in victims:
-                self._watchdog_abort(ln, uid, idx)
-            time.sleep(tick)
+            # a plain sleep wakes more cheaply than an Event wait; the
+            # stop waits for at most one tick
+            time.sleep(max(0.0, t_plan - self._now()))
+            if self._halt.is_set():
+                break
+            now = self._now()
+            gc2 = gc_probe.paused(now)
+            # one system call for the process's CPU time and counts
+            ru2 = resource.getrusage(resource.RUSAGE_SELF)
+            self._ticks.append(flight.Tick(
+                t_plan, now - t_plan,
+                ru2.ru_utime + ru2.ru_stime - ru.ru_utime - ru.ru_stime,
+                gc2 - gc_s, ru2.ru_nivcsw - ru.ru_nivcsw,
+                ru2.ru_majflt - ru.ru_majflt))
+            gc_s, ru = gc2, ru2
+            if watchdog:
+                self._watchdog_scan()
+            t_plan += tick * max(1, math.ceil((now - t_plan) / tick))
+        self._tick_cpu_s = time.thread_time() - cpu_start
+
+    def _watchdog_scan(self):
+        with self._wake:
+            if self._stop:
+                return
+            now = self._now()
+            victims = [(ln, info[0], info[1])
+                       for ln, info in self._inflight_info.items()
+                       if info[3] is not None and now - info[2] > info[3]]
+        for ln, uid, idx in victims:
+            self._watchdog_abort(ln, uid, idx)
 
     def _watchdog_abort(self, lane: int, uid: int, idx: int) -> bool:
         """Abort the gang release whose quantum is hung on ``lane``:
@@ -696,6 +753,8 @@ class GangExecutor:
             picked = self.sched.pick_next_task_rt(lane, prev, nxt)
             prev = None
             if picked is not None:
+                t_pick = self._now()
+                slot = 3 * picked.index
                 job = self._jobs[picked.task.uid]
                 # NOTE: no budget write here. Budgets are applied from
                 # the gang-change hook under g.lock (_apply_budgets); a
@@ -709,6 +768,7 @@ class GangExecutor:
                 if inst is None:
                     prev = picked
                     continue
+                inst.phase[slot + PICKED] = t_pick
                 # gang-isolation barrier: wait out other gangs' in-flight
                 # quanta. Condition-variable wakeups (notified when any
                 # quantum retires and on gang hand-offs) replace the old
@@ -727,8 +787,6 @@ class GangExecutor:
                             break
                         self._wake.wait(timeout=0.05)
                 t0 = self._now()
-                if inst.start is None:
-                    inst.start = t0
                 requeue = False
                 stalled = False
                 try:
@@ -739,6 +797,7 @@ class GangExecutor:
                         requeue = True       # preempted while stalled
                     else:
                         t_run = self._now()
+                        inst.phase[slot + ADMITTED] = t_run
                         job.fn(lane, inst.index)
                 finally:
                     if self._quantum_retired(lane):
@@ -750,13 +809,12 @@ class GangExecutor:
                     prev = picked
                     continue
                 t1 = self._now()
-                dur = t1 - t_run
                 key = job.name
                 with self._lock:
                     if inst.aborted:
                         # the watchdog killed this gang release while we
                         # ran: the late return is discarded — no sample,
-                        # no EMA poisoning, no finish
+                        # no finish
                         self.trace.record(lane, f"aborted:{key}",
                                           t0 * 1e3, t1 * 1e3)
                         prev = picked
@@ -765,12 +823,7 @@ class GangExecutor:
                         self.trace.record(lane, f"throttled:{key}",
                                           t0 * 1e3, t_run * 1e3)
                     self.trace.record(lane, key, t_run * 1e3, t1 * 1e3)
-                    ema = self._ema.get(key)
-                    if ema is not None and \
-                            dur > self.straggler_factor * ema:
-                        self.stragglers.append((key, lane, dur))
-                    self._ema[key] = dur if ema is None else \
-                        0.9 * ema + 0.1 * dur
+                    inst.phase[slot + DONE] = t1
                     inst.remaining_lanes.discard(lane)
                     if not inst.remaining_lanes and inst.finish is None:
                         inst.finish = t1
@@ -811,34 +864,34 @@ class GangExecutor:
     def run(self, duration_s: float):
         """Run the lanes for ``duration_s`` seconds and return the stats.
         The first exception any quantum raises stops every lane early and
-        is re-raised here after the workers have stopped."""
+        is re-raised here after the workers have stopped; the run's
+        flight record is published (``obs.flight.last_run()``) either
+        way.
+
+        The run sits inside one ``executor.run`` profiler annotation when
+        JAX is loaded (no profiler session can exist otherwise); its
+        opening and closing instants on the executor's clock anchor the
+        record to a profiler trace."""
+        prof = sys.modules.get("jax.profiler")
+        span = prof.TraceAnnotation("executor.run") if prof \
+            else contextlib.nullcontext()
+        tick = self._monitor_tick()
         self._t0 = time.monotonic()
-        workers = [threading.Thread(target=self._lane_main, args=(lane,),
-                                    daemon=True)
-                   for lane in range(self.n_lanes)]
-        for w in workers:
-            w.start()
-        if self._watchdog_armed():
-            deadlines = [d for d in (self._watchdog_deadline(j)
-                                     for j in self.rt_jobs)
-                         if d is not None]
-            tick = min(deadlines) / 4 if deadlines else 0.01
-            threading.Thread(target=self._watchdog_monitor,
-                             args=(min(max(tick, 0.001), 0.05),),
-                             daemon=True).start()
-        self._failed.wait(duration_s)
-        with self._wake:
-            self._stop = True
-            self._wake.notify_all()
-        for w in workers:
-            w.join(timeout=5.0)
+        with flight.GcProbe(self._now) as gc_probe:
+            opened = self._stamp(span.__enter__)
+            try:
+                self._run_lanes(duration_s, tick, gc_probe)
+            finally:
+                closed = self._stamp(
+                    lambda: span.__exit__(None, None, None))
+        anchor = (opened, closed) if prof else None
+        flight.publish(self._record(duration_s, tick, anchor, gc_probe))
         if self._error is not None:
             raise self._error
         self.trace.finish_view()
         return {
             "response_times": self.response_times,
             "be_quanta": dict(self.be_quanta),
-            "stragglers": list(self.stragglers),
             "rt_stalls": dict(self.rt_stalls),
             "preemptions": self.sched.g.preemptions,
             "acquisitions": self.sched.g.acquisitions,
@@ -849,3 +902,49 @@ class GangExecutor:
             "metrics": self.metrics.snapshot()
             if self.metrics is not None else None,
         }
+
+    def _stamp(self, fn: Callable[[], object]) -> float:
+        """Call ``fn`` and return the midpoint of the executor-clock
+        instants around it."""
+        a = self._now()
+        fn()
+        return 0.5 * (a + self._now())
+
+    def _run_lanes(self, duration_s: float, tick: float,
+                   gc_probe: flight.GcProbe):
+        workers = [threading.Thread(target=self._lane_main, args=(lane,),
+                                    daemon=True)
+                   for lane in range(self.n_lanes)]
+        monitor = threading.Thread(
+            target=self._monitor,
+            args=(tick, self._watchdog_armed(), gc_probe), daemon=True)
+        for w in workers:
+            w.start()
+        monitor.start()
+        self._failed.wait(duration_s)
+        with self._wake:
+            self._stop = True
+            self._wake.notify_all()
+        self._halt.set()
+        for w in workers:
+            w.join(timeout=5.0)
+        monitor.join(timeout=5.0)
+
+    def _record(self, duration_s: float, tick: float, anchor,
+                gc_probe: flight.GcProbe) -> flight.FlightRecord:
+        """The run's flight record, copied out of the instances, the
+        regulator's history and the rings."""
+        releases = []
+        with self._lock:
+            for job in self.rt_jobs:
+                for inst in self._instances[job.uid]:
+                    ph = inst.phase
+                    releases.extend(
+                        flight.Phase(job.name, inst.index, lane,
+                                     inst.release, *ph[3 * i:3 * i + 3])
+                        for i, lane in enumerate(job.lanes))
+        return flight.FlightRecord(
+            window_s=duration_s, tick_s=tick, releases=releases,
+            windows=flight.windows_from_history(self.reg.history),
+            ticks=list(self._ticks), gcs=list(gc_probe.rows),
+            anchor=anchor, tick_cpu_s=self._tick_cpu_s)

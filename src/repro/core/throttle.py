@@ -41,8 +41,9 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-from typing import Dict, Iterable, List, Optional, Set, Tuple
+from typing import Deque, Dict, Iterable, Optional, Set, Tuple
 
+from repro.obs.flight import ring
 from repro.obs.metrics import Counter, Gauge, MetricsRegistry
 
 _INF = float("inf")
@@ -59,6 +60,10 @@ class ThrottleState:
     # dynamic reclaiming (per-window, reset on roll — DESIGN.md §7.5)
     donated: float = 0.0         # quota pulled out of this core's window
     drawn: float = 0.0           # quota granted to this core's window
+    # the current window's charges: quanta admitted, and whether any was
+    # refused (once per window, however often the core retried)
+    admitted: int = 0
+    denied: bool = False
     # instrumentation: obs.metrics instruments — the regulator binds
     # registry-owned series (throttle.trips{core=} is on the engine
     # parity contract) or detached instances when unmetered
@@ -126,11 +131,11 @@ class BandwidthRegulator:
                 denied_total=reg.counter("throttle.denied_total", core=c),
                 overrun=reg.gauge("throttle.max_overrun", core=c))
             for c in range(n_cores)}
-        # counter-track samples for the Perfetto export (obs.perfetto):
-        # ("window", t_end, core, used, limit) per closed finite-budget
-        # window, ("draw", t, cumulative) per reclaim transfer. Opt-in:
-        # unbounded growth is wrong for long executor runs.
-        self.history: Optional[List[Tuple]] = [] if record_history else None
+        # opt-in ring of samples (obs.flight, obs.perfetto): ("window",
+        # t_end, core, used, limit, k, admitted, denied) per closed
+        # window k, ("draw", t, cumulative) per reclaim transfer
+        self.history: Optional[Deque[Tuple]] = ring() if record_history \
+            else None
         self._lock = threading.Lock()
 
     @property
@@ -208,22 +213,19 @@ class BandwidthRegulator:
     def _roll_window(self, st: ThrottleState, now: float) -> None:
         delta = now - st.window_start
         if delta >= st.interval:
-            if self.history is not None and st.budget != _INF:
-                t_end = st.window_start + st.interval
+            if self.history is not None:
                 self.history.append(
-                    ("window", t_end, st.core, st.used, st.limit))
-                if delta >= 2 * st.interval:
-                    # skipped windows carried no usage: one zero sample
-                    # steps the counter track down instead of holding
-                    self.history.append(
-                        ("window", t_end + st.interval, st.core,
-                         0.0, st.budget))
+                    ("window", st.window_start + st.interval, st.core,
+                     st.used, st.limit, round(st.window_start / st.interval),
+                     st.admitted, st.denied))
             # jump directly to the window containing ``now`` (O(1) even
             # after a long idle gap; every skipped window resets usage)
             st.window_start += int(delta / st.interval) * st.interval
             st.used = 0.0
             st.donated = 0.0
             st.drawn = 0.0
+            st.admitted = 0
+            st.denied = False
 
     def charge(self, core: int, amount: float, now: float) -> bool:
         """Account ``amount`` of traffic at time ``now``.
@@ -249,23 +251,31 @@ class BandwidthRegulator:
         st = self.cores[core]
         self._roll_window(st, now)
         if now < st.stalled_until:
-            st.denied_total.value += amount
+            st.denied = True
+            # admission: a charge refused here retries the quantum
+            # whose denial set the stall, and that denial counted it
+            if self.mode != "admission":
+                st.denied_total.value += amount
             return 0.0
         limit = st.limit
         if self.mode == "admission":
             if st.used + amount > limit:
                 st.trips.value += 1
                 st.denied_total.value += amount
+                st.denied = True
                 self._set_stall(core, st)
                 return 0.0
             st.used += amount
             st.used_total.value += amount
+            st.admitted += 1
             return 1.0
         before = st.used
         st.used += amount
         st.used_total.value += amount
+        st.admitted += 1
         if st.used > limit:
             st.trips.value += 1
+            st.denied = True
             self._note_overrun(st, before)
             self._set_stall(core, st)
             if amount <= 0.0:

@@ -19,7 +19,15 @@ Track layout:
 * pid ``PID_COUNTERS`` — "C" counter events: per-window bandwidth
   budget vs. used per core, donation-pool level under reclaim, and
   cumulative glock hold time (built by ``export_sim`` from the
-  regulator's window history and the engines' gang-change log).
+  regulator's window history and the engines' gang-change log); with
+  an executor's flight record, each lane's quanta admitted and denial
+  per regulation window (``be window lane N``).
+* pid ``PID_PHASES`` — with a flight record (``obs.flight``), one
+  thread per lane with each RT release's ``rt.pick_lag`` and
+  ``rt.gate`` spans.
+* pid ``PID_HOST`` — with a flight record, the host's late
+  ``host.tick`` wakeups (late by a tick period or more) and its
+  ``host.gc`` pauses.
 
 ``segments_from_json`` inverts the core tracks exactly (the round-trip
 test in tests/test_obs.py relies on it), and ``validate_chrome_trace``
@@ -28,10 +36,13 @@ is a dependency-free structural validator used by CI's smoke job.
 from __future__ import annotations
 
 import json
+import math
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 PID_CORES = 1
 PID_COUNTERS = 2
+PID_PHASES = 3
+PID_HOST = 4
 MS = 1000.0      # trace unit (ms) -> chrome unit (us)
 
 # Perfetto's fixed color-name palette (cname); picked for contrast:
@@ -61,13 +72,18 @@ def _classify(label: str, rt_names: Sequence[str]) -> Tuple[str, str]:
 def export_trace(trace, rt_names: Sequence[str] = (),
                  counters: Optional[Dict[str, List[Tuple[float, Dict]]]]
                  = None,
-                 title: str = "repro") -> Dict:
+                 title: str = "repro", flight=None) -> Dict:
     """Chrome-trace dict for a ``core.tracing.Trace``.
 
     ``counters`` maps track name -> [(t_ms, {series: value}), ...];
     each becomes one "C" counter track (Perfetto stacks the series).
+    ``flight``: the executor run's ``obs.flight.FlightRecord`` (stamps
+    in seconds on the same clock as the trace's milliseconds), which
+    adds the phase and host tracks and the per-window counters.
     """
     trace.finish_view()
+    if flight is not None:
+        counters = {**(counters or {}), **window_tracks(flight.windows)}
     ev: List[Dict] = [
         {"ph": "M", "pid": PID_CORES, "tid": 0, "name": "process_name",
          "args": {"name": f"{title}: cores"}},
@@ -88,6 +104,8 @@ def export_trace(trace, rt_names: Sequence[str] = (),
                    "name": s.label, "cat": cat, "cname": cname,
                    "ts": s.t0 * MS, "dur": (s.t1 - s.t0) * MS,
                    "args": {"t0_ms": s.t0, "t1_ms": s.t1}})
+    if flight is not None:
+        ev.extend(flight_events(flight, title))
     if counters:
         ev.append({"ph": "M", "pid": PID_COUNTERS, "tid": 0,
                    "name": "process_name",
@@ -102,23 +120,88 @@ def export_trace(trace, rt_names: Sequence[str] = (),
     return {"traceEvents": ev, "displayTimeUnit": "ms"}
 
 
+def _span(pid: int, tid: int, name: str, t0_s: float, t1_s: float,
+          args: Dict) -> Dict:
+    return {"ph": "X", "pid": pid, "tid": tid, "name": name,
+            "cat": name.split(".")[0], "ts": t0_s * 1e3 * MS,
+            "dur": (t1_s - t0_s) * 1e3 * MS, "args": args}
+
+
+def flight_events(rec, title: str = "repro") -> List[Dict]:
+    """The phase and host tracks of an executor run's flight record."""
+    ev: List[Dict] = []
+    for pid, name, order in ((PID_PHASES, "phases", 2),
+                             (PID_HOST, "host", 3)):
+        ev.append({"ph": "M", "pid": pid, "tid": 0, "name": "process_name",
+                   "args": {"name": f"{title}: {name}"}})
+        ev.append({"ph": "M", "pid": pid, "tid": 0,
+                   "name": "process_sort_index",
+                   "args": {"sort_index": order}})
+    for lane in sorted({p.lane for p in rec.releases}):
+        ev.append({"ph": "M", "pid": PID_PHASES, "tid": lane,
+                   "name": "thread_name", "args": {"name": f"lane {lane}"}})
+    for p in rec.releases:
+        args = {"job": p.job, "k": p.k}
+        if not math.isnan(p.picked):
+            ev.append(_span(PID_PHASES, p.lane, "rt.pick_lag", p.due,
+                            p.picked, args))
+            if not math.isnan(p.admitted):
+                ev.append(_span(PID_PHASES, p.lane, "rt.gate", p.picked,
+                                p.admitted, args))
+    for tid, name in ((0, "host.tick"), (1, "host.gc")):
+        ev.append({"ph": "M", "pid": PID_HOST, "tid": tid,
+                   "name": "thread_name", "args": {"name": name}})
+    for t in rec.ticks:
+        if t.late >= rec.tick_s:
+            ev.append(_span(PID_HOST, 0, "host.tick", t.t, t.t + t.late,
+                            {"cpu_s": t.cpu_s, "gc_s": t.gc_s,
+                             "nivcsw": t.nivcsw, "majflt": t.majflt}))
+    for g in rec.gcs:
+        ev.append(_span(PID_HOST, 1, "host.gc", g.t0, g.t1,
+                        {"generation": g.generation}))
+    return ev
+
+
 # ---- counter-track builders (regulator history + gang-change log) ----
+
+def window_tracks(windows) -> Dict[str, List[Tuple[float, Dict]]]:
+    """Per-lane ``be window lane N`` counter tracks from a flight
+    record's window rows: quanta admitted, and 1 where the window
+    denied a charge, stepped at window ends."""
+    out: Dict[str, List[Tuple[float, Dict]]] = {}
+    for w in windows:
+        out.setdefault(f"be window lane {w.lane}", []).append(
+            (w.t_end * 1e3, {"admitted": w.admitted,
+                             "denied": int(w.denied)}))
+    return out
+
 
 def bandwidth_tracks(history: Iterable[Tuple]) -> Dict[
         str, List[Tuple[float, Dict]]]:
     """Counter tracks from ``BandwidthRegulator.history`` samples.
 
-    ``("window", t_end, core, used, limit)`` samples — one closed
-    regulation window per core — become per-core ``bw core N`` tracks
-    (used vs. budget, stepped at window ends); ``("draw", t, total)``
-    samples become one cumulative ``reclaim drawn`` track.
+    ``("window", t_end, core, used, limit, k, ...)`` samples — one
+    closed regulation window ``k`` per core — become per-core ``bw core
+    N`` tracks (used vs. budget, stepped at window ends; finite budgets
+    only, and windows skipped without a charge step the track down to
+    zero); ``("draw", t, total)`` samples become one cumulative
+    ``reclaim drawn`` track.
     """
     out: Dict[str, List[Tuple[float, Dict]]] = {}
+    last: Dict[int, Tuple[float, int]] = {}      # core -> (t_end, k)
     for rec in history:
         if rec[0] == "window":
-            _, t_end, core, used, limit = rec
-            out.setdefault(f"bw core {core}", []).append(
-                (t_end, {"used": used, "budget": limit}))
+            _, t_end, core, used, limit, k = rec[:6]
+            prev = last.get(core)
+            last[core] = (t_end, k)
+            if limit == float("inf"):
+                continue
+            track = out.setdefault(f"bw core {core}", [])
+            if prev is not None and k > prev[1] + 1:
+                step = (t_end - prev[0]) / (k - prev[1])
+                track.append((prev[0] + step, {"used": 0.0,
+                                               "budget": limit}))
+            track.append((t_end, {"used": used, "budget": limit}))
         elif rec[0] == "draw":
             _, t, total = rec
             out.setdefault("reclaim drawn", []).append(

@@ -220,6 +220,45 @@ def test_perfetto_roundtrip_exact():
     assert got == want
 
 
+def test_perfetto_roundtrip_with_an_executor_flight_record():
+    """An executor run exported with its flight record: the core tracks
+    still invert exactly, and the phase, host and per-window tracks
+    carry the record's rows."""
+    import time
+    from repro.core.executor import BEJob, GangExecutor, RTJob
+    from repro.obs import flight
+    from repro.obs.perfetto import PID_HOST, PID_PHASES
+
+    ex = GangExecutor(n_lanes=2, regulation_interval_s=0.005)
+    ex.submit_rt(RTJob("rt", lambda lane, k: time.sleep(0.001), lanes=(0,),
+                       prio=5, period_s=0.01, budget_bytes=2.0))
+    ex.submit_be(BEJob("be", lambda lane: time.sleep(0.002), lanes=(0, 1),
+                       bytes_per_quantum=1.0))
+    ex.run(0.3)
+    rec = flight.last_run()
+    data = json.loads(json.dumps(export_trace(
+        ex.trace, rt_names=["rt"], title="executor", flight=rec)))
+    assert validate_chrome_trace(data) == []
+    want = sorted(((s.core, s.label, s.t0, s.t1)
+                   for s in ex.trace.segments if s.label is not None),
+                  key=lambda t: (t[0], t[2]))
+    assert segments_from_json(data) == want
+    evs = data["traceEvents"]
+    phases = [e for e in evs if e["ph"] == "X" and e["pid"] == PID_PHASES]
+    picked = [p for p in rec.releases if p.picked == p.picked]
+    assert sum(e["name"] == "rt.pick_lag" for e in phases) == len(picked)
+    gate = next(e for e in phases if e["name"] == "rt.gate")
+    p = next(p for p in picked if p.k == gate["args"]["k"])
+    assert gate["ts"] == pytest.approx(p.picked * 1e6)
+    assert gate["dur"] == pytest.approx(p.gate * 1e6)
+    host = [e for e in evs if e["ph"] == "X" and e["pid"] == PID_HOST]
+    assert sum(e["name"] == "host.gc" for e in host) == len(rec.gcs)
+    windows = [e for e in evs if e["ph"] == "C"
+               and e["name"] == "be window lane 1"]
+    assert len(windows) == sum(w.lane == 1 for w in rec.windows) > 0
+    assert set(windows[0]["args"]) == {"admitted", "denied"}
+
+
 def test_perfetto_span_classification_and_counter_tracks():
     sim, r = run(fig5_taskset, None, record_counters=True)
     data = export_sim(sim, r, title="fig5")
